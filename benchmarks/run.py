@@ -113,6 +113,8 @@ def main(argv=None) -> None:
             sys.exit("usage: run.py [--quick] [--json PATH] "
                      "[--profile DIR] [--suite NAME[,NAME...]]")
         only = [s for s in argv[i + 1].split(",") if s]
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (bench_training_time, bench_convergence,
                             bench_bottleneck, bench_action_space,
                             bench_end_to_end, bench_finetune, roofline,

@@ -523,7 +523,7 @@ def _solve_fleet_rates(params: SimParams, table: ScheduleTable, threads,
     reference solve, "pallas" routes the SAME solve through the fused
     contention kernel (repro.kernels.contention — caps, scaled floors, and
     the proportional residual split in one VMEM-resident kernel;
-    interpret-mode off-TPU, pinned against the reference at 1e-5 in
+    interpret mode on the CPU, pinned against the reference at 1e-5 in
     tests/test_fleet.py and tests/test_fleet_scaleout.py). ``rounds=0``:
     the single-bottleneck fleet model does not water-fill."""
     if backend == "pallas":

@@ -654,6 +654,12 @@ def train_ppo(env_params, cfg: PPOConfig = None, *, workload=None,
     key = key if key is not None else jax.random.PRNGKey(cfg.seed)
     k_init, key = jax.random.split(key)
     train_state = init_agent(k_init, cfg)
+    if mesh is not None:
+        # replicated on the mesh from round 0: the episode returns it so,
+        # and a carry whose placement changed after round 0 would recompile
+        from jax.sharding import NamedSharding, PartitionSpec
+        train_state = jax.device_put(
+            train_state, NamedSharding(mesh, PartitionSpec()))
     topo_mode = wl.topology is not None or resample_topology is not None
     scheduled = wl.tables is not None or resample is not None or topo_mode
     # defaults are filled per round AFTER resampling, from these constants

@@ -323,7 +323,7 @@ def _solve_topology_rates(params: SimParams, graph: LinkGraph,
     reference solve; "pallas" fuses the whole per-substep solve — caps,
     scaled floors, proportional residual split, the F-round water-fill,
     and the min-over-path-links — into the repro.kernels.contention kernel
-    (interpret-mode off-TPU; pinned vs the reference in tests)."""
+    (interpret mode on the CPU; pinned vs the reference in tests)."""
     if backend == "pallas":
         from repro.kernels.contention.ops import contention_rates
         dt = params.duration / substeps

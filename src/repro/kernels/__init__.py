@@ -8,26 +8,31 @@
                     (the paper's own hot loop: offline PPO training)
 
 Each kernel ships kernel.py (pl.pallas_call + BlockSpec), ops.py (jit'd
-wrapper; interpret=True on non-TPU platforms) and ref.py (pure-jnp oracle).
+wrapper; compiled on TPU, interpreted on CPU) and ref.py (pure-jnp oracle).
 """
 
 from __future__ import annotations
 
+import jax
 
-def tpu_compiler_params(**kwargs):
-    """Version-compat accessor for the Mosaic TPU compiler-params class:
-    newer JAX spells it ``pltpu.CompilerParams``, older releases (including
-    the pinned 0.4.x) ``pltpu.TPUCompilerParams``. Returns an instance built
-    from ``kwargs``, or None when neither spelling exists / accepts them —
-    the semantics only affect TPU compilation, so None is always safe."""
-    from jax.experimental.pallas import tpu as pltpu
 
-    for name in ("CompilerParams", "TPUCompilerParams"):
-        cls = getattr(pltpu, name, None)
-        if cls is None:
-            continue
-        try:
-            return cls(**kwargs)
-        except TypeError:  # field drift across versions
-            continue
-    return None
+def default_interpret() -> bool:
+    """The kernel wrappers' ``interpret=None`` default: compiled Mosaic on a
+    TPU, the Pallas interpreter on the CPU (tests, CPU rehearsals). Any
+    other platform has no path here and raises rather than silently
+    interpreting on an accelerator."""
+    platform = jax.default_backend()
+    if platform == "tpu":
+        return False
+    if platform == "cpu":
+        return True
+    raise RuntimeError(f"Pallas kernels here compile for TPU or interpret "
+                       f"on CPU; no path for platform {platform!r}")
+
+
+def compiled_kernel_in(hlo_text: str, name: str) -> bool:
+    """Whether a compiled program's text holds kernel ``name`` as a Mosaic
+    custom call, i.e. compiled for the TPU. An interpreted kernel leaves its
+    name only in the op metadata of ordinary XLA ops."""
+    return any(name in line and 'custom_call_target="tpu_custom_call"' in line
+               for line in hlo_text.splitlines())

@@ -9,8 +9,12 @@ and the min-over-path-links combine) into one VMEM-resident program per
 substep: one HBM read of the window inputs and one (F, 3) write back, no
 intermediate (S, F, E, 3) tensors ever materialized in HBM.
 
-The grid iterates the S substeps; flows and links live entirely in VMEM
-(f32 tiles — the F axis rides the 8-sublane dimension, stages the lanes).
+The grid iterates the S substeps; flows and links live entirely in VMEM.
+Inside the kernel every tensor is stage-major, (3, E, F): flows ride the
+128 lanes, links the 8 sublanes, and the stage is an untiled leading axis.
+Every reduction keeps its axis, so no value ever moves between lanes and
+sublanes — the TPU compiler refuses such shape casts. The wrapper re-lays
+the operands into that form and the (3, 1, F) result back to (F, 3).
 The schedule gathers (table bins -> per-substep tpt/bw, activity windows ->
 act, route bins -> onpath) happen OUTSIDE the kernel: they are cheap
 order-preserving gathers and keeping them out makes the kernel a pure
@@ -30,53 +34,53 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from repro.kernels import tpu_compiler_params
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _contention_kernel(threads_ref, act_ref, onpath_ref, tpt_ref, bw_ref,
                        floor_ref, cap_ref, out_ref, *, with_objectives,
                        rounds):
-    threads = threads_ref[...]                         # (F, 3)
-    act = act_ref[0]                                   # (F,)
-    onpath = onpath_ref[0]                             # (F, E)
-    tpt = tpt_ref[0]                                   # (E, 3)
-    bw = bw_ref[0]                                     # (E, 3)
+    threads = threads_ref[...]                         # (3, 1, F)
+    act = act_ref[0]                                   # (1, 1, F)
+    onpath = onpath_ref[0]                             # (1, E, F)
+    tpt = tpt_ref[0]                                   # (3, E, 1)
+    bw = bw_ref[0]                                     # (3, E, 1)
     # effective threads of flow f ON link e (0 off-path / inactive)
-    eff = (threads[:, None, :] * act[:, None, None]
-           * onpath[:, :, None])                       # (F, E, 3)
-    total = jnp.maximum(eff.sum(axis=0), 1e-9)         # (E, 3)
-    share = eff / total[None]
+    eff = threads * act * onpath                       # (3, E, F)
+    total = jnp.maximum(eff.sum(axis=-1, keepdims=True), 1e-9)  # (3, E, 1)
+    share = eff / total
     if not with_objectives:
-        link_rate = jnp.minimum(eff * tpt[None], share * bw[None])
+        link_rate = jnp.minimum(eff * tpt, share * bw)
     else:
-        floor = floor_ref[...][:, None, :]             # (F, 1, 3)
-        cap = cap_ref[...][:, None, :]                 # (F, 1, 3)
-        demand = jnp.minimum(eff * tpt[None], cap)     # (F, E, 3)
+        floor = floor_ref[...]                         # (3, 1, F)
+        cap = cap_ref[...]                             # (3, 1, F)
+        demand = jnp.minimum(eff * tpt, cap)           # (3, E, F)
         guaranteed = jnp.minimum(floor, demand)
-        g_tot = guaranteed.sum(axis=0)                 # (E, 3)
+        g_tot = guaranteed.sum(axis=-1, keepdims=True)  # (3, E, 1)
         guaranteed = guaranteed * jnp.minimum(
-            1.0, bw / jnp.maximum(g_tot, 1e-9))[None]
-        residual = jnp.maximum(bw - guaranteed.sum(axis=0), 0.0)
-        alloc = share * residual[None]
+            1.0, bw / jnp.maximum(g_tot, 1e-9))
+        residual = jnp.maximum(
+            bw - guaranteed.sum(axis=-1, keepdims=True), 0.0)
+        alloc = share * residual
         headroom = cap - guaranteed                    # inf when uncapped
         if rounds:
             def body(_, alloc):
-                spill = jnp.maximum(alloc - headroom, 0.0).sum(axis=0)
+                spill = jnp.maximum(alloc - headroom, 0.0).sum(
+                    axis=-1, keepdims=True)
                 alloc = jnp.minimum(alloc, headroom)
                 w = eff * (alloc < headroom)
-                w_tot = jnp.maximum(w.sum(axis=0), 1e-9)
-                return alloc + (w / w_tot[None]) * spill[None]
+                w_tot = jnp.maximum(w.sum(axis=-1, keepdims=True), 1e-9)
+                return alloc + (w / w_tot) * spill
 
             alloc = jax.lax.fori_loop(0, rounds, body, alloc)
             alloc = jnp.minimum(alloc, headroom)
         link_rate = jnp.minimum(demand, guaranteed + alloc)
     # end-to-end rate: min over the flow's links (off-path never
     # constrains), empty paths and inactive flows move exactly nothing
-    constraining = jnp.where(onpath[:, :, None] > 0, link_rate, jnp.inf)
-    rate = jnp.min(constraining, axis=1)               # (F, 3)
-    has_path = onpath.sum(axis=1) > 0
-    out_ref[0] = jnp.where(has_path[:, None], rate, 0.0) * act[:, None]
+    constraining = jnp.where(onpath > 0, link_rate, jnp.inf)
+    rate = jnp.min(constraining, axis=1, keepdims=True)  # (3, 1, F)
+    has_path = onpath.sum(axis=1, keepdims=True) > 0     # (1, 1, F)
+    out_ref[0] = jnp.where(has_path, rate, 0.0) * act
 
 
 def contention_rates_pallas(threads, act, onpath, tpt, bw, floor, cap, *,
@@ -88,27 +92,32 @@ def contention_rates_pallas(threads, act, onpath, tpt, bw, floor, cap, *,
     kernel = functools.partial(_contention_kernel,
                                with_objectives=with_objectives,
                                rounds=rounds)
-    params = None if interpret else tpu_compiler_params(
-        dimension_semantics=("arbitrary",))
-    extra = {} if params is None else {"compiler_params": params}
-    return pl.pallas_call(
+
+    def per_flow(x):                                   # (F, 3) -> (3, 1, F)
+        return x.astype(jnp.float32).T[:, None, :]
+
+    def per_link(x):                                   # (S, E, 3) -> (S, 3, E, 1)
+        return jnp.swapaxes(x.astype(jnp.float32), 1, 2)[..., None]
+
+    out = pl.pallas_call(
         kernel,
         grid=(S,),
         in_specs=[
-            pl.BlockSpec((F, 3), lambda i: (0, 0)),
-            pl.BlockSpec((1, F), lambda i: (i, 0)),
-            pl.BlockSpec((1, F, E), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, E, 3), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, E, 3), lambda i: (i, 0, 0)),
-            pl.BlockSpec((F, 3), lambda i: (0, 0)),
-            pl.BlockSpec((F, 3), lambda i: (0, 0)),
+            pl.BlockSpec((3, 1, F), lambda i: (0, 0, 0)),
+            pl.BlockSpec((1, 1, 1, F), lambda i: (i, 0, 0, 0)),
+            pl.BlockSpec((1, 1, E, F), lambda i: (i, 0, 0, 0)),
+            pl.BlockSpec((1, 3, E, 1), lambda i: (i, 0, 0, 0)),
+            pl.BlockSpec((1, 3, E, 1), lambda i: (i, 0, 0, 0)),
+            pl.BlockSpec((3, 1, F), lambda i: (0, 0, 0)),
+            pl.BlockSpec((3, 1, F), lambda i: (0, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, F, 3), lambda i: (i, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((S, F, 3), jnp.float32),
+        out_specs=pl.BlockSpec((1, 3, 1, F), lambda i: (i, 0, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((S, 3, 1, F), jnp.float32),
         interpret=interpret,
         name="contention_solve",
-        **extra,
-    )(threads.astype(jnp.float32), act.astype(jnp.float32),
-      onpath.astype(jnp.float32), tpt.astype(jnp.float32),
-      bw.astype(jnp.float32), floor.astype(jnp.float32),
-      cap.astype(jnp.float32))
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+    )(per_flow(threads), act.astype(jnp.float32)[:, None, None, :],
+      jnp.swapaxes(onpath.astype(jnp.float32), 1, 2)[:, None],
+      per_link(tpt), per_link(bw), per_flow(floor), per_flow(cap))
+    return jnp.transpose(out[:, :, 0, :], (0, 2, 1))   # (S, F, 3)

@@ -7,6 +7,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import default_interpret
 from repro.kernels.contention.kernel import contention_rates_pallas
 
 
@@ -23,11 +24,10 @@ def contention_rates(threads, act, onpath, tpt, bw, floor=None, cap=None, *,
     ``floor``/``cap``: optional (F,) per-flow rate floor/cap (None = the
     objective-free solve, a structurally smaller kernel). ``rounds``:
     static water-fill spill rounds (0 = no redistribution — fleet
-    semantics). ``interpret`` defaults to True off-TPU so CPU tier-1 runs
-    the kernel in interpreter mode; compiled-TPU coverage stays behind the
-    ``pallas`` pytest marker."""
+    semantics). ``interpret`` defaults to compiled on a TPU and the
+    interpreter on the CPU (``repro.kernels.default_interpret``)."""
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = default_interpret()
     F = threads.shape[0]
     with_objectives = floor is not None or cap is not None
     floor = jnp.zeros((F,), jnp.float32) if floor is None else floor

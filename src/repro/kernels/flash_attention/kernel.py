@@ -100,8 +100,7 @@ def flash_attention_bhsd(q, k, v, *, causal=True, window=None,
                                n_kv=n_kv, causal=causal, window=window,
                                scale=scale)
     grid = (B, Hq, n_q, n_kv)
-    from repro.kernels import tpu_compiler_params
-    cparams = tpu_compiler_params(
+    cparams = pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "parallel",
                              "arbitrary"))
     return pl.pallas_call(

@@ -1,6 +1,6 @@
 """jit'd wrapper around the flash-attention Pallas kernel: model-layout
 (B, S, H, D) in/out, padding to block multiples, GQA via head-group
-index-mapping, interpret mode on non-TPU platforms."""
+index-mapping, interpret mode on the CPU."""
 
 from __future__ import annotations
 
@@ -9,11 +9,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import default_interpret
 from repro.kernels.flash_attention.kernel import flash_attention_bhsd
-
-
-def _should_interpret():
-    return jax.default_backend() != "tpu"
 
 
 @partial(jax.jit, static_argnames=("causal", "window", "blk_q", "blk_k",
@@ -23,7 +20,7 @@ def flash_attention(q, k, v, q_pos=None, k_pos=None, *, causal=True,
     """q: (B,S,Hq,D); k/v: (B,Skv,Hkv,D). Positions are assumed contiguous
     from 0 (training/prefill path); decode uses the cache path instead."""
     if interpret is None:
-        interpret = _should_interpret()
+        interpret = default_interpret()
     B, S, Hq, D = q.shape
     Skv, Hkv = k.shape[1], k.shape[2]
     blk_q = min(blk_q, S)

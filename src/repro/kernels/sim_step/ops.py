@@ -7,11 +7,14 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import default_interpret
 from repro.kernels.sim_step.kernel import sim_step_pallas, sim_interval_pallas
 
 
 def _pick_blk(E):
-    for cand in (256, 128, 64, 32, 16, 8, 4, 2, 1):
+    """Env block: a lane-aligned divisor of E, else all of E — a TPU block's
+    minor dim must be a multiple of 128 or span the whole array."""
+    for cand in (256, 128):
         if E % cand == 0:
             return cand
     return E
@@ -21,7 +24,7 @@ def _pick_blk(E):
 def sim_step_batch(bufs, rate, cap, *, substeps=50, duration=1.0,
                    interpret=None):
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = default_interpret()
     return sim_step_pallas(bufs, rate, cap, substeps=substeps,
                            duration=duration, blk=_pick_blk(bufs.shape[0]),
                            interpret=interpret)
@@ -34,7 +37,7 @@ def sim_interval_batch(bufs, rates_dt, cap, *, interpret=None):
     here (per-env under vmap — the pallas batching rule folds the env batch
     into the grid)."""
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = default_interpret()
     return sim_interval_pallas(bufs, rates_dt, cap,
                                blk=_pick_blk(bufs.shape[0]),
                                interpret=interpret)

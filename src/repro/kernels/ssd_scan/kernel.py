@@ -80,8 +80,7 @@ def ssd_scan_pallas(x, dt, A, B, C, *, chunk=128, interpret=True):
 
     kernel = functools.partial(_ssd_kernel, Q=Q)
     grid = (b, h, nc)
-    from repro.kernels import tpu_compiler_params
-    cparams = tpu_compiler_params(
+    cparams = pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"))
     return pl.pallas_call(
         kernel,

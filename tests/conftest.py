@@ -1,8 +1,6 @@
 import os
 import time
 
-import pytest
-
 # Tests run on the single real CPU device (the 512-device fake platform is
 # ONLY for the dry-run, set inside repro.launch.dryrun before jax init).
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
@@ -33,21 +31,6 @@ def _is_tier1_selection(config) -> bool:
 def pytest_configure(config):
     global _session_t0
     _session_t0 = time.monotonic()
-
-
-def pytest_collection_modifyitems(config, items):
-    """pallas-marked tests need a compiled-Pallas-compatible accelerator;
-    skip them cleanly on CPU-only hosts (PALLAS_TESTS=1 forces them on)."""
-    if os.environ.get("PALLAS_TESTS"):
-        return
-    import jax
-    if jax.default_backend() != "cpu":
-        return
-    skip = pytest.mark.skip(
-        reason="pallas: no compatible accelerator (PALLAS_TESTS=1 to force)")
-    for item in items:
-        if "pallas" in item.keywords:
-            item.add_marker(skip)
 
 
 def pytest_runtest_logreport(report):

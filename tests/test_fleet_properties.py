@@ -309,9 +309,13 @@ def test_all_inactive_substeps_move_zero_bytes_every_path(data):
     # every window strictly after the simulated interval [0, duration)
     flows = make_flow_schedule([float(params.duration) + 1.0] * F,
                                [np.inf] * F)
+    # float32 levels without subnormals: XLA flushes those to zero in
+    # arithmetic (CPU and TPU alike), so the dense path's "+ 0.0 moved"
+    # would zero a level that the sparse path's pass-through keeps
     buffers = jnp.asarray(
-        [[data.draw(st.floats(0.0, 0.4)) for _ in range(2)]
-         for _ in range(F)], jnp.float32)
+        [[data.draw(st.floats(0.0, float(np.float32(0.4)), width=32,
+                              allow_subnormal=False))
+          for _ in range(2)] for _ in range(F)], jnp.float32)
     for kw in ({}, {"max_active": max(F - 1, 1)}, {"backend": "pallas"},
                {"backend": "pallas", "max_active": max(F - 1, 1)}):
         if kw.get("max_active", F) >= F:

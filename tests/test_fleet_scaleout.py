@@ -287,19 +287,6 @@ def test_topology_pallas_backend_matches_dense():
                                    atol=2e-5)
 
 
-@pytest.mark.pallas
-def test_contention_kernel_compiled_on_accelerator():
-    """Compiled (non-interpret) contention kernel on a real accelerator —
-    auto-skipped on hosts without one (see conftest)."""
-    threads, act, onpath, tpt, bw, floor, cap = _kernel_operands(0)
-    want = np.asarray(contention_rates_reference(
-        threads, act, onpath, tpt, bw, floor, cap, rounds=5))
-    got = np.asarray(contention_rates(threads, act, onpath, tpt, bw,
-                                      floor, cap, rounds=5,
-                                      interpret=False))
-    np.testing.assert_allclose(got, want, atol=1e-4)
-
-
 # ---------------------------------------------------------------------------
 # Power-of-two padding: reward-exact, and compile count stays flat
 # ---------------------------------------------------------------------------

@@ -36,29 +36,32 @@ pytestmark = pytest.mark.online
 OBJ = ObservationSpec(context=True, fleet=True, objectives=True)
 
 # actions of the PRE-online-layer FleetController/AutoMDTController on the
-# seeded observation streams below, as int64 little-endian hex — captured
-# before this PR touched the controllers
+# seeded observation streams below, as int64 little-endian hex. First
+# captured before the online layer touched the controllers; re-captured
+# under JAX 0.9's default RNG stream (jax_threefry_partitionable=True),
+# after checking that the earlier capture still reproduced exactly with
+# jax_threefry_partitionable=False — only the key stream changed.
 GOLD_FLEET = (
-    "13000000000000001a0000000000000017000000000000001000000000000000"
-    "170000000000000020000000000000001c000000000000001500000000000000"
+    "1000000000000000190000000000000018000000000000001a00000000000000"
     "1800000000000000140000000000000015000000000000001600000000000000"
-    "180000000000000019000000000000001a000000000000001900000000000000"
-    "1400000000000000190000000000000016000000000000001900000000000000"
-    "1d00000000000000190000000000000019000000000000000e00000000000000"
-    "17000000000000001a000000000000001a000000000000001c00000000000000"
-    "2400000000000000160000000000000016000000000000001c00000000000000"
-    "130000000000000015000000000000001a000000000000000e00000000000000"
-    "170000000000000013000000000000001a000000000000001500000000000000"
-    "1d0000000000000016000000000000001a000000000000001b00000000000000"
-    "1800000000000000150000000000000023000000000000001700000000000000"
-    "1d000000000000001b000000000000001c000000000000001500000000000000"
-    "17000000000000001a00000000000000")
+    "2000000000000000170000000000000019000000000000001300000000000000"
+    "16000000000000001c0000000000000015000000000000001100000000000000"
+    "14000000000000001d000000000000001d000000000000001800000000000000"
+    "1b0000000000000018000000000000001c000000000000001300000000000000"
+    "1300000000000000180000000000000014000000000000001400000000000000"
+    "1d000000000000001f000000000000001d000000000000002400000000000000"
+    "1400000000000000140000000000000013000000000000001900000000000000"
+    "2100000000000000190000000000000019000000000000001400000000000000"
+    "17000000000000001b0000000000000019000000000000001500000000000000"
+    "220000000000000014000000000000001f000000000000001b00000000000000"
+    "1c000000000000001f0000000000000017000000000000000d00000000000000"
+    "1d000000000000001800000000000000")
 GOLD_AUTO = (
-    "17000000000000001b0000000000000017000000000000001300000000000000"
-    "1f0000000000000018000000000000001c000000000000001b00000000000000"
-    "1600000000000000180000000000000019000000000000001100000000000000"
-    "1700000000000000150000000000000019000000000000001b00000000000000"
-    "19000000000000002100000000000000")
+    "140000000000000016000000000000001a000000000000002000000000000000"
+    "1e000000000000001d000000000000001a000000000000001e00000000000000"
+    "150000000000000017000000000000001f000000000000001500000000000000"
+    "1700000000000000190000000000000013000000000000001400000000000000"
+    "1d000000000000001e00000000000000")
 
 
 def _fleet_obs_stream(rng, steps=6, n_flows=3):

@@ -126,20 +126,32 @@ def test_controller_drives_real_engine_to_completion():
 
 def test_training_driver_end_to_end(tmp_path):
     """~100M-family (smollm) reduced config: tuned input pipeline +
-    fault-tolerant loop; loss decreases. The threaded pipeline groups rows
-    into batches in arrival order, so per-step losses jitter run-to-run
-    (~0.02): assert the TREND over head/tail windows, where the ~0.05
-    decrease at 30 steps clears the noise, not two single samples."""
-    import numpy as np
+    fault-tolerant loop; loss decreases. The corpus is uniform random
+    tokens, so per-batch losses scatter by ~0.04 around a trend far
+    smaller than that at 30 warm-up steps: whether a head/tail window
+    comparison passes depends on the PRNG stream, not on learning. The
+    trend is read instead on one fixed held-out batch, scored with the
+    initial and the final params — no batch-sampling noise, and a drop
+    only a parameter update can make."""
     from repro.configs import get_smoke_config
+    from repro.launch.steps import init_state
     from repro.launch.train import train
+    from repro.models import get_model
     cfg = get_smoke_config("smollm-135m")
-    _, info = train(cfg, steps=30, batch=4, seq=64,
-                    ckpt_dir=str(tmp_path / "ckpt"), controller="globus",
-                    log_every=0)
+    final, info = train(cfg, steps=30, batch=4, seq=64,
+                        ckpt_dir=str(tmp_path / "ckpt"), controller="globus",
+                        log_every=0)
     losses = np.asarray(info["losses"])
     assert len(losses) == 30
-    assert np.mean(losses[-5:]) < np.mean(losses[:5]), losses
+    assert np.isfinite(losses).all(), losses
+    rows = np.random.default_rng(12345).integers(0, cfg.vocab, (32, 65),
+                                                 dtype=np.int32)
+    held = {"tokens": rows[:, :-1], "labels": rows[:, 1:]}
+    loss_fn = get_model(cfg).loss_fn
+    before = float(loss_fn(init_state(cfg, jax.random.PRNGKey(0))["params"],
+                           held)[0])
+    after = float(loss_fn(final["params"], held)[0])
+    assert after < before, (before, after)
     assert info["report"].checkpoints >= 1
 
 

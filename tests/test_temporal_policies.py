@@ -23,10 +23,10 @@ from repro.core.simulator import (make_env_params, env_reset, env_step,
                                   DEFAULT_OBS, CONTEXT_OBS, history_init,
                                   history_push, history_flatten)
 
-# Same golden as tests/test_unified_env.py — captured at PR 1 HEAD from the
-# pre-refactor static path; the temporal stack must leave it untouched.
-GOLDEN_HISTORY = [9.479823, 9.608167, 9.315872, 9.577387,
-                  9.189676, 9.723083, 9.806993, 9.53947]
+# The golden of tests/test_unified_env.py (the pre-refactor static path,
+# re-captured there under JAX 0.9's default RNG stream); the temporal stack
+# must leave it untouched.
+from tests.test_unified_env import GOLDEN_HISTORY
 
 
 def _params():

@@ -19,19 +19,24 @@ from repro.core.simulator import (make_env_params, sim_interval, env_reset,
 # ---------------------------------------------------------------------------
 # Goldens captured from the PRE-refactor static path (PR 1 HEAD, seed repo
 # dual-stack code) — the unified schedule-native core must reproduce them.
+# GOLDEN_HISTORY and GOLDEN_RESET_THREADS draw on the PRNG key stream: they
+# were re-captured under JAX 0.9's default stream
+# (jax_threefry_partitionable=True) after checking that the earlier values
+# ([9.479823, ...] and [6, 14, 8]) still reproduced exactly with
+# jax_threefry_partitionable=False — only the key stream changed.
 # ---------------------------------------------------------------------------
 
 # train_ppo on tpt=[0.08,0.16,0.2], bw=1, cap=2, n_max=50,
 # PPOConfig(max_episodes=8, n_envs=4, max_steps=5, seed=0)
-GOLDEN_HISTORY = [9.479823, 9.608167, 9.315872, 9.577387,
-                  9.189676, 9.723083, 9.806993, 9.53947]
+GOLDEN_HISTORY = [8.858108, 9.023705, 9.158712, 8.839299,
+                  8.916402, 8.633703, 8.926790, 9.131025]
 
 # 3x sim_interval on tpt=[0.2,0.05,0.2], bw=2, cap=0.5, threads=[8,4,2]
 GOLDEN_BUFS = [0.4959999918937683, 0.0]
 GOLDEN_TPS = [0.20000040531158447, 0.20000000298023224, 0.20000000298023224]
 
 # env_reset(PRNGKey(42)) + env_step([9,9,9]) on the train_ppo params above
-GOLDEN_RESET_THREADS = [6.0, 14.0, 8.0]
+GOLDEN_RESET_THREADS = [10.0, 10.0, 7.0]
 GOLDEN_OBS = [0.18, 0.18, 0.18, 0.72, 0.72, 0.72, 1.0, 1.0]
 GOLDEN_REWARD = 1.807391
 
@@ -243,16 +248,3 @@ def test_deprecated_pr1_aliases_are_gone():
                  "dyn_env_step", "DynSimEnv", "DynEnvState"):
         assert not hasattr(sim, name), name
     assert not hasattr(ppo, "train_ppo_scenarios")
-
-
-@pytest.mark.pallas
-def test_pallas_backend_compiled_on_accelerator():
-    """Compiled (non-interpret) Pallas on a real accelerator — auto-skipped
-    on hosts without one (see conftest)."""
-    from repro.kernels.sim_step.ops import sim_interval_batch
-    bufs = jnp.zeros((8, 2))
-    rates = jnp.full((8, 50, 3), 0.004)
-    cap = jnp.full((8, 2), 0.5)
-    nb, moved = sim_interval_batch(bufs, rates, cap, interpret=False)
-    assert nb.shape == (8, 2) and moved.shape == (8, 3)
-    assert np.isfinite(np.asarray(moved)).all()
