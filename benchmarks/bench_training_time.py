@@ -193,8 +193,6 @@ def main(rows=None):
     ]
     backend_rows(rows)
     policy_rows(rows)
-    # fleet_scaling_rows runs as its own run.py suite (so --profile can
-    # wrap just the scale-out timeline)
     return rows
 
 

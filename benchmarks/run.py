@@ -67,10 +67,6 @@ available.
 ``--json PATH`` additionally writes every row to PATH as JSON — CI uploads
 the quick rows as a ``BENCH_<pr>.json`` artifact per PR, the repo's
 benchmark trajectory (see README).
-
-``--profile DIR`` wraps the fleet-scaling suite in ``jax.profiler.trace``
-and writes the trace to DIR (open with TensorBoard / Perfetto) — the
-scale-out rows are the ones worth a timeline when chasing a regression.
 """
 
 from __future__ import annotations
@@ -97,21 +93,14 @@ def main(argv=None) -> None:
         i = argv.index("--json")
         if i + 1 >= len(argv) or argv[i + 1].startswith("-"):
             sys.exit("usage: run.py [--quick] [--json PATH] "
-                     "[--profile DIR]")
+                     "[--suite NAME[,NAME...]]")
         json_path = argv[i + 1]
-    profile_dir = None
-    if "--profile" in argv:
-        i = argv.index("--profile")
-        if i + 1 >= len(argv) or argv[i + 1].startswith("-"):
-            sys.exit("usage: run.py [--quick] [--json PATH] "
-                     "[--profile DIR] [--suite NAME[,NAME...]]")
-        profile_dir = argv[i + 1]
     only = None
     if "--suite" in argv:
         i = argv.index("--suite")
         if i + 1 >= len(argv) or argv[i + 1].startswith("-"):
             sys.exit("usage: run.py [--quick] [--json PATH] "
-                     "[--profile DIR] [--suite NAME[,NAME...]]")
+                     "[--suite NAME[,NAME...]]")
         only = [s for s in argv[i + 1].split(",") if s]
     from repro.launch.compile_cache import enable_compile_cache
     enable_compile_cache()
@@ -121,18 +110,6 @@ def main(argv=None) -> None:
                             bench_scenarios, bench_fleet, bench_objectives,
                             bench_topology, bench_faults, bench_controller,
                             bench_online)
-    def _maybe_profiled(fn):
-        """Wrap the fleet-scaling suite in a jax.profiler trace when
-        --profile DIR was given."""
-        if profile_dir is None:
-            return fn
-
-        def wrapped(rows):
-            import jax
-            with jax.profiler.trace(profile_dir):
-                return fn(rows)
-        return wrapped
-
     if quick:
         suites = [
             ("training_time_backends",
@@ -142,9 +119,8 @@ def main(argv=None) -> None:
              lambda rows: bench_training_time.policy_rows(rows, n_envs=4,
                                                           iters=2)),
             ("fleet_scaling_quick",
-             _maybe_profiled(lambda rows: bench_training_time.
-                             fleet_scaling_rows(rows, iters=2,
-                                                pallas_max_f=64))),
+             lambda rows: bench_training_time.fleet_scaling_rows(
+                 rows, iters=2, pallas_max_f=64)),
             ("scenarios_quick",
              lambda rows: bench_scenarios.main(rows, quick=True)),
             ("fleet_quick",
@@ -164,8 +140,7 @@ def main(argv=None) -> None:
     else:
         suites = [
             ("training_time", bench_training_time.main),
-            ("fleet_scaling",
-             _maybe_profiled(bench_training_time.fleet_scaling_rows)),
+            ("fleet_scaling", bench_training_time.fleet_scaling_rows),
             ("convergence", bench_convergence.main),
             ("bottleneck", bench_bottleneck.main),
             ("action_space", bench_action_space.main),

@@ -70,6 +70,7 @@ from repro.core.fleet import (fleet_reset, fleet_step, fleet_observe,
                               pad_flow_objectives)
 from repro.core.topology import (topology_reset, topology_step,
                                  topology_observe, Topology, pad_path_spec)
+from repro.core.tracing import span
 from repro.core.schedule import constant_table
 from repro.core.simulator import (env_reset, env_step, observe, ACT_DIM,
                                   ObservationSpec, DEFAULT_OBS,
@@ -456,7 +457,9 @@ def _make_episode_fn(env_params, cfg: PPOConfig, *, randomize_t0,
     new schedule VALUES never retrace. ``topology`` (static flag) swaps the
     rollout for the multi-link twin: the ``topo`` arg (batched Topology,
     leading axis n_envs) replaces ``tables`` as the world, and the fleet
-    batch shaping applies for any n_flows >= 1."""
+    batch shaping applies for any n_flows >= 1. The rollout and the
+    ``ppo_epochs`` update run under the named scopes ``rollout`` and
+    ``update``: op metadata only, the compiled numbers are the same."""
     spec = effective_obs_spec(cfg)
     recurrent = cfg.policy == "gru"
     fleet = cfg.n_flows > 1 and not topology
@@ -467,39 +470,39 @@ def _make_episode_fn(env_params, cfg: PPOConfig, *, randomize_t0,
         params, opt = train_state["params"], train_state["opt"]
         k_roll, _ = jax.random.split(key)
         roll_keys = jax.random.split(k_roll, cfg.n_envs)
-        if topology:
-            obs, act, rew, logp = jax.vmap(
-                lambda tp, fl, ob, k: _rollout_topology(
-                    params["policy"], env_params, tp, fl, ob, k,
-                    M=cfg.max_steps, substeps=cfg.substeps, spec=spec,
-                    backend=cfg.backend, randomize_t0=randomize_t0,
-                    policy=cfg.policy, n_flows=cfg.n_flows,
-                    fairness_coef=cfg.fairness_coef,
-                    deadline_coef=cfg.deadline_coef,
-                    max_active=cfg.max_active)
-            )(topo, flows, objectives, roll_keys)
-            # (E, M, F, ...) / rew (E, M)
-        elif fleet:
-            obs, act, rew, logp = jax.vmap(
-                lambda tab, fl, ob, k: _rollout_fleet(
-                    params["policy"], env_params, tab, fl, ob, k,
-                    M=cfg.max_steps, substeps=cfg.substeps, spec=spec,
-                    backend=cfg.backend, randomize_t0=randomize_t0,
-                    policy=cfg.policy, n_flows=cfg.n_flows,
-                    fairness_coef=cfg.fairness_coef,
-                    deadline_coef=cfg.deadline_coef,
-                    max_active=cfg.max_active)
-            )(tables, flows, objectives, roll_keys)
-            # (E, M, F, ...) / rew (E, M)
-        else:
-            obs, act, rew, logp = jax.vmap(
-                lambda tab, k: _rollout(params["policy"], env_params, tab, k,
-                                        M=cfg.max_steps,
-                                        substeps=cfg.substeps,
-                                        spec=spec, backend=cfg.backend,
-                                        randomize_t0=randomize_t0,
-                                        policy=cfg.policy)
-            )(tables, roll_keys)  # (E, M, ...)
+        with jax.named_scope("rollout"):
+            if topology:
+                obs, act, rew, logp = jax.vmap(
+                    lambda tp, fl, ob, k: _rollout_topology(
+                        params["policy"], env_params, tp, fl, ob, k,
+                        M=cfg.max_steps, substeps=cfg.substeps, spec=spec,
+                        backend=cfg.backend, randomize_t0=randomize_t0,
+                        policy=cfg.policy, n_flows=cfg.n_flows,
+                        fairness_coef=cfg.fairness_coef,
+                        deadline_coef=cfg.deadline_coef,
+                        max_active=cfg.max_active)
+                )(topo, flows, objectives, roll_keys)
+                # (E, M, F, ...) / rew (E, M)
+            elif fleet:
+                obs, act, rew, logp = jax.vmap(
+                    lambda tab, fl, ob, k: _rollout_fleet(
+                        params["policy"], env_params, tab, fl, ob, k,
+                        M=cfg.max_steps, substeps=cfg.substeps, spec=spec,
+                        backend=cfg.backend, randomize_t0=randomize_t0,
+                        policy=cfg.policy, n_flows=cfg.n_flows,
+                        fairness_coef=cfg.fairness_coef,
+                        deadline_coef=cfg.deadline_coef,
+                        max_active=cfg.max_active)
+                )(tables, flows, objectives, roll_keys)
+                # (E, M, F, ...) / rew (E, M)
+            else:
+                obs, act, rew, logp = jax.vmap(
+                    lambda tab, k: _rollout(
+                        params["policy"], env_params, tab, k,
+                        M=cfg.max_steps, substeps=cfg.substeps, spec=spec,
+                        backend=cfg.backend, randomize_t0=randomize_t0,
+                        policy=cfg.policy)
+                )(tables, roll_keys)  # (E, M, ...)
         if cfg.gae_lambda == 1.0:  # static: the paper's Monte-Carlo path
             ret = jax.vmap(_returns, in_axes=(0, None))(rew, cfg.gamma)
             if multi:
@@ -564,8 +567,9 @@ def _make_episode_fn(env_params, cfg: PPOConfig, *, randomize_t0,
                                           max_grad_norm=cfg.max_grad_norm)
             return (params, opt), l
 
-        (params, opt), losses = jax.lax.scan(update, (params, opt), None,
-                                             length=cfg.ppo_epochs)
+        with jax.named_scope("update"):
+            (params, opt), losses = jax.lax.scan(update, (params, opt), None,
+                                                 length=cfg.ppo_epochs)
         ep_rewards = rew.sum(axis=1)  # (E,)
         return ({"params": params, "opt": opt}, ep_rewards, losses[-1])
 
@@ -691,83 +695,95 @@ def train_ppo(env_params, cfg: PPOConfig = None, *, workload=None,
     warned_table_resample = False
 
     while n_episodes < cfg.max_episodes:
-        if resample is not None and ((wl.tables is None
-                                      and wl.topology is None) or rnd > 0):
-            out = resample(rnd)
-            if isinstance(out, Workload):
-                wl = out
-            else:  # legacy fn(round) -> batched tables
-                if not warned_table_resample:
-                    warned_table_resample = True
-                    warnings.warn(
-                        "train_ppo(resample=...) returning bare tables is "
-                        "deprecated: return a repro.core.Workload",
-                        DeprecationWarning, stacklevel=2)
-                wl = wl.replace(tables=out)
-        if resample_flows is not None and (wl.flows is None or rnd > 0):
-            wl = wl.replace(flows=resample_flows(rnd))
-        if resample_objectives is not None and (wl.objectives is None
-                                                or rnd > 0):
-            wl = wl.replace(objectives=resample_objectives(rnd))
-        if resample_topology is not None and (wl.topology is None or rnd > 0):
-            wl = wl.replace(topology=resample_topology(rnd))
-        if resample_faults is not None and (wl.faults is None or rnd > 0):
-            wl = wl.replace(faults=resample_faults(rnd))
-        run = wl.compiled()  # fault edits (no faults -> wl itself)
-        tables_r = run.tables if run.tables is not None else fill_tables
-        flows_r = run.flows if run.flows is not None else fill_flows
-        objectives_r, topology_r = run.objectives, run.topology
-        if pad_to is not None and flows_r is not None:
-            flows_r = pad_flow_schedule(flows_r, pad_to)
-            objectives_r = pad_flow_objectives(objectives_r, pad_to)
-            if topology_r is not None:
-                topology_r = Topology(graph=topology_r.graph,
-                                      paths=pad_path_spec(topology_r.paths,
-                                                          pad_to))
-        if mesh is not None:
-            from repro.sharding.fleet import (shard_flow_schedule,
-                                              shard_flow_objectives,
-                                              shard_path_spec)
-            if flows_r is not None:
-                flows_r = shard_flow_schedule(flows_r, mesh)
-            objectives_r = shard_flow_objectives(objectives_r, mesh)
-            if topology_r is not None:
-                topology_r = Topology(graph=topology_r.graph,
-                                      paths=shard_path_spec(topology_r.paths,
-                                                            mesh))
-        rnd += 1
-        key, k = jax.random.split(key)
-        train_state, ep_rewards, loss = episode_fn(train_state, tables_r,
-                                                   flows_r, objectives_r,
-                                                   topology_r, k)
-        ep_rewards = jax.device_get(ep_rewards)
-        if by_batch_mean:
-            batch_mean = float(ep_rewards.mean())
-            if batch_mean > best_sel:
-                best_sel = batch_mean
-                best_params = jax.device_get(train_state["params"])
-                stagnant = 0
-            else:
-                stagnant += len(ep_rewards)
-        for r in ep_rewards:
-            n_episodes += 1
-            history.append(float(r))
-            if r > best_r:
-                best_r = float(r)
-                if not by_batch_mean:
-                    best_params = jax.device_get(train_state["params"])
-                    stagnant = 0
-            elif not by_batch_mean:
-                stagnant += 1
-        if cfg.log_every and n_episodes % cfg.log_every < cfg.n_envs:
-            print(f"[ppo] ep={n_episodes} best={best_r:.3f} "
-                  f"loss={float(loss):.3f}", flush=True)
-        if r_max is not None:
-            if (converged_at is None
-                    and best_r >= cfg.convergence_frac * r_max * cfg.max_steps):
-                converged_at = n_episodes
-            if converged_at is not None and stagnant >= cfg.patience:
-                break
+        # one step-view span per round; its self time is the resample and
+        # Workload.compiled()
+        with span("ppo.round", step_num=rnd):
+            if resample is not None and ((wl.tables is None
+                                          and wl.topology is None) or rnd > 0):
+                out = resample(rnd)
+                if isinstance(out, Workload):
+                    wl = out
+                else:  # legacy fn(round) -> batched tables
+                    if not warned_table_resample:
+                        warned_table_resample = True
+                        warnings.warn(
+                            "train_ppo(resample=...) returning bare tables "
+                            "is deprecated: return a repro.core.Workload",
+                            DeprecationWarning, stacklevel=2)
+                    wl = wl.replace(tables=out)
+            if resample_flows is not None and (wl.flows is None or rnd > 0):
+                wl = wl.replace(flows=resample_flows(rnd))
+            if resample_objectives is not None and (wl.objectives is None
+                                                    or rnd > 0):
+                wl = wl.replace(objectives=resample_objectives(rnd))
+            if resample_topology is not None and (wl.topology is None
+                                                  or rnd > 0):
+                wl = wl.replace(topology=resample_topology(rnd))
+            if resample_faults is not None and (wl.faults is None or rnd > 0):
+                wl = wl.replace(faults=resample_faults(rnd))
+            run = wl.compiled()  # fault edits (no faults -> wl itself)
+            tables_r = run.tables if run.tables is not None else fill_tables
+            flows_r = run.flows if run.flows is not None else fill_flows
+            objectives_r, topology_r = run.objectives, run.topology
+            if pad_to is not None and flows_r is not None:
+                flows_r = pad_flow_schedule(flows_r, pad_to)
+                objectives_r = pad_flow_objectives(objectives_r, pad_to)
+                if topology_r is not None:
+                    topology_r = Topology(
+                        graph=topology_r.graph,
+                        paths=pad_path_spec(topology_r.paths, pad_to))
+            if mesh is not None:
+                from repro.sharding.fleet import (shard_flow_schedule,
+                                                  shard_flow_objectives,
+                                                  shard_path_spec)
+                if flows_r is not None:
+                    flows_r = shard_flow_schedule(flows_r, mesh)
+                objectives_r = shard_flow_objectives(objectives_r, mesh)
+                if topology_r is not None:
+                    topology_r = Topology(
+                        graph=topology_r.graph,
+                        paths=shard_path_spec(topology_r.paths, mesh))
+            rnd += 1
+            with span("ppo.dispatch"):
+                key, k = jax.random.split(key)
+                train_state, ep_rewards, loss = episode_fn(
+                    train_state, tables_r, flows_r, objectives_r, topology_r,
+                    k)
+            with span("ppo.rewards"):
+                ep_rewards = jax.device_get(ep_rewards)
+            with span("ppo.select"):
+                if by_batch_mean:
+                    batch_mean = float(ep_rewards.mean())
+                    if batch_mean > best_sel:
+                        best_sel = batch_mean
+                        with span("ppo.best_copy"):
+                            best_params = jax.device_get(
+                                train_state["params"])
+                        stagnant = 0
+                    else:
+                        stagnant += len(ep_rewards)
+                for r in ep_rewards:
+                    n_episodes += 1
+                    history.append(float(r))
+                    if r > best_r:
+                        best_r = float(r)
+                        if not by_batch_mean:
+                            with span("ppo.best_copy"):
+                                best_params = jax.device_get(
+                                    train_state["params"])
+                            stagnant = 0
+                    elif not by_batch_mean:
+                        stagnant += 1
+                if cfg.log_every and n_episodes % cfg.log_every < cfg.n_envs:
+                    print(f"[ppo] ep={n_episodes} best={best_r:.3f} "
+                          f"loss={float(loss):.3f}", flush=True)
+                if r_max is not None:
+                    if (converged_at is None
+                            and best_r >= (cfg.convergence_frac * r_max
+                                           * cfg.max_steps)):
+                        converged_at = n_episodes
+                    if converged_at is not None and stagnant >= cfg.patience:
+                        break
 
     return TrainResult(params=best_params, episodes=n_episodes,
                        wall_s=time.time() - t0, history=history,
