@@ -70,7 +70,7 @@ from repro.core.fleet import (fleet_reset, fleet_step, fleet_observe,
                               pad_flow_objectives)
 from repro.core.topology import (topology_reset, topology_step,
                                  topology_observe, Topology, pad_path_spec)
-from repro.core.tracing import span
+from repro.core.tracing import mark, span
 from repro.core.schedule import constant_table
 from repro.core.simulator import (env_reset, env_step, observe, ACT_DIM,
                                   ObservationSpec, DEFAULT_OBS,
@@ -595,6 +595,16 @@ def train_ppo(env_params, cfg: PPOConfig = None, *, workload=None,
     """Algorithm 2, schedule-native. Returns TrainResult with the BEST (not
     last) params.
 
+    Rounds run one ahead: round r+1 is dispatched (its resample, its key
+    and its episode program, queued on round r's output state) before
+    round r's rewards are read back and selected on, so the host's work
+    overlaps the device's. ``ceil(max_episodes / n_envs)`` rounds run, and
+    the result is the one a round-at-a-time loop gives, bit for bit. The
+    one difference a caller sees: ``resample(r + 1)`` is called before
+    round r's convergence test, so a run that stops on ``r_max`` and
+    ``patience`` calls ``resample`` once more, for a round it drops
+    unread.
+
     ``workload``: a ``repro.core.Workload`` bundling everything one round
     runs on — batched ScheduleTable (leading axis cfg.n_envs; None = the
     params' static conditions), batched FlowSchedule activity windows
@@ -690,65 +700,85 @@ def train_ppo(env_params, cfg: PPOConfig = None, *, workload=None,
     history = []
     t0 = time.time()
     n_episodes = 0
-    rnd = 0
+    n_rounds = max(0, -(-cfg.max_episodes // cfg.n_envs))
     by_batch_mean = cfg.param_selection == "batch_mean"
     warned_table_resample = False
 
-    while n_episodes < cfg.max_episodes:
-        # one step-view span per round; its self time is the resample and
-        # Workload.compiled()
+    def world(rnd):
+        """Round ``rnd``'s inputs: the resample, ``Workload.compiled()``,
+        the defaults, flow padding and mesh placement."""
+        nonlocal wl, warned_table_resample
+        if resample is not None and ((wl.tables is None
+                                      and wl.topology is None) or rnd > 0):
+            out = resample(rnd)
+            if isinstance(out, Workload):
+                wl = out
+            else:  # legacy fn(round) -> batched tables
+                if not warned_table_resample:
+                    warned_table_resample = True
+                    warnings.warn(
+                        "train_ppo(resample=...) returning bare tables "
+                        "is deprecated: return a repro.core.Workload",
+                        DeprecationWarning, stacklevel=4)
+                wl = wl.replace(tables=out)
+        if resample_flows is not None and (wl.flows is None or rnd > 0):
+            wl = wl.replace(flows=resample_flows(rnd))
+        if resample_objectives is not None and (wl.objectives is None
+                                                or rnd > 0):
+            wl = wl.replace(objectives=resample_objectives(rnd))
+        if resample_topology is not None and (wl.topology is None
+                                              or rnd > 0):
+            wl = wl.replace(topology=resample_topology(rnd))
+        if resample_faults is not None and (wl.faults is None or rnd > 0):
+            wl = wl.replace(faults=resample_faults(rnd))
+        run = wl.compiled()  # fault edits (no faults -> wl itself)
+        tables_r = run.tables if run.tables is not None else fill_tables
+        flows_r = run.flows if run.flows is not None else fill_flows
+        objectives_r, topology_r = run.objectives, run.topology
+        if pad_to is not None and flows_r is not None:
+            flows_r = pad_flow_schedule(flows_r, pad_to)
+            objectives_r = pad_flow_objectives(objectives_r, pad_to)
+            if topology_r is not None:
+                topology_r = Topology(
+                    graph=topology_r.graph,
+                    paths=pad_path_spec(topology_r.paths, pad_to))
+        if mesh is not None:
+            from repro.sharding.fleet import (shard_flow_schedule,
+                                              shard_flow_objectives,
+                                              shard_path_spec)
+            if flows_r is not None:
+                flows_r = shard_flow_schedule(flows_r, mesh)
+            objectives_r = shard_flow_objectives(objectives_r, mesh)
+            if topology_r is not None:
+                topology_r = Topology(
+                    graph=topology_r.graph,
+                    paths=shard_path_spec(topology_r.paths, mesh))
+        return tables_r, flows_r, objectives_r, topology_r
+
+    def dispatch(rnd, state, prev_rewards=None):
+        """Round ``rnd``'s episode program, queued behind the round whose
+        outputs (``state``, ``prev_rewards``) it starts from."""
+        nonlocal key
+        inputs = world(rnd)
+        if prev_rewards is not None and not prev_rewards.is_ready():
+            mark("ppo.dispatch_ahead")
+        with span("ppo.dispatch"):
+            key, k = jax.random.split(key)
+            return episode_fn(state, *inputs, k)
+
+    # one round in flight ahead of the one being read: round r+1 is
+    # dispatched before round r's rewards are copied back, so the host's
+    # dispatch, read-back and selection run while the device works
+    ahead = None
+    for rnd in range(n_rounds):
+        # one step-view span per round: round r's read-back and selection,
+        # after round r+1's world and dispatch (round 0's own too, in the
+        # first); its self time is the resample and Workload.compiled()
         with span("ppo.round", step_num=rnd):
-            if resample is not None and ((wl.tables is None
-                                          and wl.topology is None) or rnd > 0):
-                out = resample(rnd)
-                if isinstance(out, Workload):
-                    wl = out
-                else:  # legacy fn(round) -> batched tables
-                    if not warned_table_resample:
-                        warned_table_resample = True
-                        warnings.warn(
-                            "train_ppo(resample=...) returning bare tables "
-                            "is deprecated: return a repro.core.Workload",
-                            DeprecationWarning, stacklevel=2)
-                    wl = wl.replace(tables=out)
-            if resample_flows is not None and (wl.flows is None or rnd > 0):
-                wl = wl.replace(flows=resample_flows(rnd))
-            if resample_objectives is not None and (wl.objectives is None
-                                                    or rnd > 0):
-                wl = wl.replace(objectives=resample_objectives(rnd))
-            if resample_topology is not None and (wl.topology is None
-                                                  or rnd > 0):
-                wl = wl.replace(topology=resample_topology(rnd))
-            if resample_faults is not None and (wl.faults is None or rnd > 0):
-                wl = wl.replace(faults=resample_faults(rnd))
-            run = wl.compiled()  # fault edits (no faults -> wl itself)
-            tables_r = run.tables if run.tables is not None else fill_tables
-            flows_r = run.flows if run.flows is not None else fill_flows
-            objectives_r, topology_r = run.objectives, run.topology
-            if pad_to is not None and flows_r is not None:
-                flows_r = pad_flow_schedule(flows_r, pad_to)
-                objectives_r = pad_flow_objectives(objectives_r, pad_to)
-                if topology_r is not None:
-                    topology_r = Topology(
-                        graph=topology_r.graph,
-                        paths=pad_path_spec(topology_r.paths, pad_to))
-            if mesh is not None:
-                from repro.sharding.fleet import (shard_flow_schedule,
-                                                  shard_flow_objectives,
-                                                  shard_path_spec)
-                if flows_r is not None:
-                    flows_r = shard_flow_schedule(flows_r, mesh)
-                objectives_r = shard_flow_objectives(objectives_r, mesh)
-                if topology_r is not None:
-                    topology_r = Topology(
-                        graph=topology_r.graph,
-                        paths=shard_path_spec(topology_r.paths, mesh))
-            rnd += 1
-            with span("ppo.dispatch"):
-                key, k = jax.random.split(key)
-                train_state, ep_rewards, loss = episode_fn(
-                    train_state, tables_r, flows_r, objectives_r, topology_r,
-                    k)
+            state_r, ep_rewards, loss = (ahead if ahead is not None
+                                         else dispatch(0, train_state))
+            ahead = (dispatch(rnd + 1, state_r, ep_rewards)
+                     if rnd + 1 < n_rounds else None)
             with span("ppo.rewards"):
                 ep_rewards = jax.device_get(ep_rewards)
             with span("ppo.select"):
@@ -757,8 +787,7 @@ def train_ppo(env_params, cfg: PPOConfig = None, *, workload=None,
                     if batch_mean > best_sel:
                         best_sel = batch_mean
                         with span("ppo.best_copy"):
-                            best_params = jax.device_get(
-                                train_state["params"])
+                            best_params = jax.device_get(state_r["params"])
                         stagnant = 0
                     else:
                         stagnant += len(ep_rewards)
@@ -770,7 +799,7 @@ def train_ppo(env_params, cfg: PPOConfig = None, *, workload=None,
                         if not by_batch_mean:
                             with span("ppo.best_copy"):
                                 best_params = jax.device_get(
-                                    train_state["params"])
+                                    state_r["params"])
                             stagnant = 0
                     elif not by_batch_mean:
                         stagnant += 1
@@ -783,7 +812,7 @@ def train_ppo(env_params, cfg: PPOConfig = None, *, workload=None,
                                            * cfg.max_steps)):
                         converged_at = n_episodes
                     if converged_at is not None and stagnant >= cfg.patience:
-                        break
+                        break  # the round in flight is dropped unread
 
     return TrainResult(params=best_params, episodes=n_episodes,
                        wall_s=time.time() - t0, history=history,

@@ -7,7 +7,8 @@ the same clock as the device's ops (TensorBoard, Perfetto), and it records
 bounded in-memory ring, so a reader in the same process can lay the phases
 against a trace or a timer of its own. ``parent`` is the name of the span
 open on the same thread when this one opened (None at the top). A block
-that raises still closes and records its span.
+that raises still closes and records its span. ``mark(name)`` records an
+instant the same way, as a span of zero length.
 
 There is no switch: with no profiler running a span costs a few
 microseconds, so spans mark phases that run once per training round, never
@@ -22,8 +23,8 @@ import time
 
 from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
-# the most recent spans kept; at about five spans a round this is some
-# thirteen thousand rounds
+# the most recent spans kept; at about six spans and marks a round this is
+# some ten thousand rounds
 RING_SIZE = 1 << 16
 
 _ring = collections.deque(maxlen=RING_SIZE)
@@ -56,6 +57,18 @@ class span:
         _ring.append((self.name, self._start, end, self._parent))
         _stack().pop()
         return False
+
+
+def mark(name):
+    """An instant: the event ``name`` happened now. Recorded in the ring
+    as a zero-length entry ``(name, t, t, parent)`` and emitted as a
+    zero-length ``TraceAnnotation``; being zero-length, it is never the
+    innermost span open over an interval."""
+    stack = _stack()
+    parent = stack[-1] if stack else None
+    with TraceAnnotation(name):
+        t = time.perf_counter_ns()
+    _ring.append((name, t, t, parent))
 
 
 def _stack():

@@ -1,6 +1,7 @@
 """The trainer's host spans (``repro.core.tracing``): nesting and parent
-links, the ring's bound, spans that close on an exception, the per-round
-phases of ``train_ppo``, and the episode program's named scopes."""
+links, the ring's bound, spans that close on an exception, marks, the
+per-round phases of ``train_ppo``, and the episode program's named
+scopes."""
 
 import glob
 import os
@@ -101,6 +102,41 @@ def test_spans_land_in_a_profiler_trace(tmp_path):
     assert any(n.startswith("ppo.round") for n in names)
 
 
+def test_a_mark_is_a_zero_length_entry_under_its_parent():
+    t0 = time.perf_counter_ns()
+    tracing.mark("top")
+    with tracing.span("outer"):
+        tracing.mark("ping")
+        with tracing.span("inner"):
+            pass
+    got = _since(t0)
+    assert [(n, p) for n, _, _, p in got] == [
+        ("top", None), ("ping", "outer"), ("inner", "outer"),
+        ("outer", None)]
+    by = {n: (s, e) for n, s, e, _ in got}
+    assert by["ping"][0] == by["ping"][1]
+    assert by["outer"][0] <= by["ping"][0] <= by["inner"][0]
+
+
+def test_a_mark_lands_in_a_profiler_trace_with_its_ring_entry(tmp_path):
+    from jax.profiler import ProfileData
+    t0 = time.perf_counter_ns()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tracing.span("ppo.round", step_num=3):
+            tracing.mark("ppo.dispatch_ahead")
+    finally:
+        jax.profiler.stop_trace()
+    assert [(n, s == e, p) for n, s, e, p in _since(t0)] == [
+        ("ppo.dispatch_ahead", True, "ppo.round"),
+        ("ppo.round", False, None)]
+    files = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                      recursive=True)
+    names = {ev.name for plane in ProfileData.from_file(files[0]).planes
+             for line in plane.lines for ev in line.events}
+    assert "ppo.dispatch_ahead" in names
+
+
 def _params():
     return make_env_params(tpt=[0.08, 0.16, 0.2], bw=[1, 1, 1], cap=[2, 2],
                            n_max=50)
@@ -118,22 +154,33 @@ def tiny_run():
 
 
 def test_train_ppo_spans_each_round_in_its_phases(tiny_run):
+    """Round r's span holds round r+1's dispatch (round 0's holds its own
+    first), then round r's read-back and selection."""
     res, got = tiny_run
     rounds = sorted((s, e) for n, s, e, _ in got if n == "ppo.round")
     assert len(rounds) == res.episodes // _cfg().n_envs == 2
-    for s, e in rounds:
+    for r, (s, e) in enumerate(rounds):
         inside = sorted((cs, n, p) for n, cs, ce, p in got
                         if s <= cs and ce <= e and n != "ppo.round")
-        phases = [(n, p) for _, n, p in inside if p == "ppo.round"]
-        assert phases == [("ppo.dispatch", "ppo.round"),
-                          ("ppo.rewards", "ppo.round"),
-                          ("ppo.select", "ppo.round")]
+        phases = [(n, p) for _, n, p in inside
+                  if p == "ppo.round" and n != "ppo.dispatch_ahead"]
+        dispatches = (r == 0) + (r + 1 < len(rounds))
+        assert phases == [("ppo.dispatch", "ppo.round")] * dispatches + [
+            ("ppo.rewards", "ppo.round"), ("ppo.select", "ppo.round")]
         # every best-param copy lies in the selection
         assert all(p == "ppo.select" for _, n, p in inside
                    if n == "ppo.best_copy")
     copies = [n for n, *_ in got if n == "ppo.best_copy"]
     # the first round always finds a best; 8 episodes give at most 8
     assert 1 <= len(copies) <= 8
+    # a dispatch ahead is marked, as an instant in the round, just before
+    # the dispatch of a round after the first
+    marks = [(s, e, p) for n, s, e, p in got if n == "ppo.dispatch_ahead"]
+    assert len(marks) <= len(rounds) - 1
+    dispatch = sorted(s for n, s, _, _ in got if n == "ppo.dispatch")
+    for s, e, p in marks:
+        assert s == e and p == "ppo.round"
+        assert s <= dispatch[-1] and s > dispatch[0]
 
 
 def test_train_ppo_history_is_unchanged_by_its_spans(tiny_run):
