@@ -9,10 +9,9 @@ For each seed it drives the cell's timed path through set-up exactly as
 the configuration's precision (``program``). On the control seeds it also
 puts the reference in the program's place: computed with float8 operands
 (``control_float8``), at float32 HIGHEST (``witness_f32_highest``), and
-with each fault planted (``fault_half_batch``: half of the batch left out,
-the means taken over the rest; ``fault_state_unchanged``;
-``fault_reward_altered``: every reward 1% larger). One JSON object per
-line.
+with each fault that the configuration's driver names in ``faults``
+planted (``fault_<name>``; the single-flow driver's are ``half_batch``,
+``state_unchanged`` and ``reward_altered``). One JSON object per line.
 """
 
 from __future__ import annotations
@@ -84,13 +83,10 @@ def seed_readings(config, traffic, seed, controls):
     yield "program", readings(prog, ref)
     if seed not in controls:
         return
-    n = traffic["n_envs"] * config["agent"]["max_steps"]
-    import jax.numpy as jnp
+    faults = spec.driver_of(config).faults(config, traffic)
     for kind, kw in (("control_float8", {"dtype": "float8"}),
                      ("witness_f32_highest", {"dtype": "float32"}),
-                     ("fault_half_batch", {"keep": jnp.arange(n) < n // 2}),
-                     ("fault_state_unchanged", {"frozen": True}),
-                     ("fault_reward_altered", {"reward_scale": 1.01})):
+                     *((f"fault_{name}", kw) for name, kw in faults.items())):
         other = train.reference(config, traffic, seed, **kw)
         yield kind, readings(other, ref)
 

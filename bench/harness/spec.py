@@ -1,11 +1,13 @@
 """Finds a cell's pieces by name: the cell in ``BENCHMARK.json``, its
-configuration file, its traffic file under ``bench/traffic/<name>.json``,
-and the readers of its per-layer metrics under
-``bench/metrics/<name>.py``. Adding a cell or a metric adds files and
-entries; nothing here changes."""
+configuration file, the driver that configuration names under
+``bench/drivers/<name>.py``, its traffic file under
+``bench/traffic/<name>.json``, and the readers of its per-layer metrics
+under ``bench/metrics/<name>.py``. Adding a cell, a deployment or a
+metric adds files and entries; nothing here changes."""
 
 from __future__ import annotations
 
+import functools
 import importlib.util
 import json
 from pathlib import Path
@@ -56,6 +58,40 @@ def load_reader(name, bench_dir=BENCH_DIR):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod.read
+
+
+DRIVER_FUNCTIONS = ("trainer", "reference", "faults", "round_flops",
+                    "steps_per_round")
+
+
+@functools.cache
+def load_driver(name, bench_dir=BENCH_DIR):
+    """The driver module ``name``: what one deployment kind is to the
+    training harness (``harness.train``), as ``DRIVER_FUNCTIONS``. A name
+    with no driver file, or a file without all of them, is an error. Each
+    driver is loaded once in a process, so set-up, the reference and the
+    counts all use the one module."""
+    path = Path(bench_dir) / "drivers" / f"{name}.py"
+    if not path.is_file():
+        raise KeyError(f"no driver named {name!r} ({path} does not exist)")
+    spec = importlib.util.spec_from_file_location(
+        "bench_driver_" + name.replace(".", "_").replace("-", "_"), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    missing = [f for f in DRIVER_FUNCTIONS if not callable(getattr(mod, f,
+                                                                   None))]
+    if missing:
+        raise KeyError(f"driver {name!r} lacks {missing}")
+    return mod
+
+
+def driver_of(config):
+    """The driver the configuration names in its ``"driver"`` key; there
+    is no default."""
+    if "driver" not in config:
+        raise KeyError(f"configuration {config.get('name')!r} names no "
+                       f"\"driver\"")
+    return load_driver(config["driver"])
 
 
 def load_peaks(kind, bench_dir=BENCH_DIR):

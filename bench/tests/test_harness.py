@@ -28,8 +28,54 @@ def test_every_cell_finds_its_pieces():
         assert config["matmul_precision"] in train.PRECISION
         for kind in ("end_to_end", "per_layer"):
             assert spec.metrics_for(bench, cell["name"], kind)
+        driver = spec.driver_of(config)
+        assert all(callable(getattr(driver, f))
+                   for f in spec.DRIVER_FUNCTIONS)
+        args, kwargs = driver.trainer(config, traffic, 2 ** 31 + 1)
+        assert isinstance(args, tuple) and isinstance(kwargs, dict)
+        steps = driver.steps_per_round(config, traffic)
+        assert isinstance(steps, int) and steps > 0
+        assert driver.round_flops(config, traffic) > 0
     for m in bench["per_layer"]:
         assert callable(spec.load_reader(m["name"]))
+
+
+@pytest.mark.parametrize("driver", ["no_such_driver", None])
+def test_a_configuration_without_a_known_driver_is_an_error(driver):
+    bench = spec.load_benchmark()
+    config = spec.load_config(bench, bench["configs"][0]["name"])
+    if driver is None:
+        del config["driver"]
+    else:
+        config["driver"] = driver
+    with pytest.raises(KeyError, match="driver"):
+        spec.driver_of(config)
+    with pytest.raises(KeyError, match="driver"):
+        train.reference(config, spec.load_traffic(
+            bench["workloads"][0]["traffic"]), 1)
+
+
+def test_a_driver_without_all_its_functions_is_an_error(tmp_path):
+    (tmp_path / "drivers").mkdir()
+    (tmp_path / "drivers" / "half.py").write_text(
+        "def trainer(config, traffic, seed):\n    return (), {}\n")
+    with pytest.raises(KeyError, match="lacks"):
+        spec.load_driver("half", bench_dir=tmp_path)
+
+
+@pytest.mark.parametrize("placed", [False, True])
+def test_shapes_lower_the_same_program_as_the_arrays(placed):
+    """``temp_bytes`` lowers from the arguments' shapes, which a donated
+    state still has: the program text is the one the arrays give."""
+    import jax
+    import jax.numpy as jnp
+    x = jnp.ones((4, 8))
+    if placed:
+        x = jax.device_put(x, jax.devices()[0])
+    f = jax.jit(lambda s: {"w": s["w"] @ s["w"].T})
+    want = f.lower({"w": x}).as_text()
+    x.delete()
+    assert f.lower({"w": train._shape_of(x)}).as_text() == want
 
 
 def test_unknown_names_are_errors():
