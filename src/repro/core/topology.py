@@ -370,10 +370,13 @@ def _sparse_topology_interval(params: SimParams, graph, paths, buffers,
         onpath=jnp.where(valid[None, :, None], paths.onpath[:, safe], 0.0),
         bin_seconds=paths.bin_seconds)
     c_bufs = jnp.where(valid[:, None], buffers[safe], 0.0)
-    rates = _solve_topology_rates(params, graph, c_paths, c_threads,
-                                  c_flows, t0, substeps, c_objs, backend,
-                                  water_fill="sorted")
-    c_bufs, c_tps = _integrate_fleet_rates(params, c_bufs, rates, backend)
+    with jax.named_scope("topology.solve"):
+        rates = _solve_topology_rates(params, graph, c_paths, c_threads,
+                                      c_flows, t0, substeps, c_objs, backend,
+                                      water_fill="sorted")
+    with jax.named_scope("topology.integrate"):
+        c_bufs, c_tps = _integrate_fleet_rates(params, c_bufs, rates,
+                                               backend)
     new_buffers = buffers.at[idx].set(c_bufs, mode="drop")
     tps = jnp.zeros_like(threads).at[idx].set(c_tps, mode="drop")
     if return_compact:
@@ -398,9 +401,11 @@ def topology_interval(params: SimParams, buffers, threads, t0=0.0, *,
         return _sparse_topology_interval(params, graph, paths, buffers,
                                          threads, t0, flows, substeps,
                                          backend, objectives, max_active)
-    rates = _solve_topology_rates(params, graph, paths, threads, flows,
-                                  t0, substeps, objectives, backend)
-    return _integrate_fleet_rates(params, buffers, rates, backend)
+    with jax.named_scope("topology.solve"):
+        rates = _solve_topology_rates(params, graph, paths, threads, flows,
+                                      t0, substeps, objectives, backend)
+    with jax.named_scope("topology.integrate"):
+        return _integrate_fleet_rates(params, buffers, rates, backend)
 
 
 def pad_path_spec(paths: PathSpec, n_to: int) -> PathSpec:
@@ -497,21 +502,22 @@ def topology_observe(params: SimParams, state: TopologyState, *,
     ``fleet_observe`` bit-for-bit. ``max_active``: optional static
     concurrency bound — the feature program runs on the compact gathered
     set only (see ``fleet_observe`` for the contract)."""
-    if max_active is not None and max_active < state.threads.shape[0]:
-        return _sparse_topology_observe(params, state, flows=flows,
-                                        graph=graph, paths=paths, spec=spec,
-                                        objectives=objectives,
-                                        max_active=max_active)
-    bw_ref = graph_peak_bw(graph)
-    base = fleet_observe(params, state, flows=flows, spec=spec,
-                         objectives=objectives, bw_ref=bw_ref)
-    if not getattr(spec, "topology", False):
-        return base
-    onpath = routes_at(paths, state.t)                 # (F, E)
-    act = active_at(flows, state.t)
-    topo = topology_features(onpath, state.throughputs[:, 1], act,
-                             link_peak_bw(graph))
-    return jnp.concatenate([base, topo], axis=-1)
+    with jax.named_scope("topology.observe"):
+        if max_active is not None and max_active < state.threads.shape[0]:
+            return _sparse_topology_observe(params, state, flows=flows,
+                                            graph=graph, paths=paths,
+                                            spec=spec, objectives=objectives,
+                                            max_active=max_active)
+        bw_ref = graph_peak_bw(graph)
+        base = fleet_observe(params, state, flows=flows, spec=spec,
+                             objectives=objectives, bw_ref=bw_ref)
+        if not getattr(spec, "topology", False):
+            return base
+        onpath = routes_at(paths, state.t)             # (F, E)
+        act = active_at(flows, state.t)
+        topo = topology_features(onpath, state.throughputs[:, 1], act,
+                                 link_peak_bw(graph))
+        return jnp.concatenate([base, topo], axis=-1)
 
 
 @partial(jax.jit, static_argnames=("n_flows", "substeps", "spec", "backend",
@@ -549,7 +555,12 @@ def topology_step(params: SimParams, state: TopologyState, actions, *,
     """actions (F, 3) -> round -> clamp [1, n_max]; one ``duration``-second
     interval over the graph. Returns (state', obs (F, frame_dim), reward).
     The reward is the shared fleet objective (``_fleet_reward`` — ONE
-    definition), normalized by the graph peak."""
+    definition), normalized by the graph peak. The rate solve, the buffer
+    integration, the observation and the reward run under the named
+    scopes ``topology.solve``, ``topology.integrate``, ``topology.observe``
+    and ``topology.reward`` (``topology_interval`` and ``topology_observe``
+    open the first three): op metadata only, the compiled numbers are the
+    same."""
     if flows is None:
         flows = always_on(state.threads.shape[0])
     threads = jnp.clip(jnp.round(actions), 1.0, params.n_max)
@@ -572,22 +583,24 @@ def topology_step(params: SimParams, state: TopologyState, actions, *,
         buffers=buffers, threads=threads, throughputs=tps,
         t=state.t + params.duration, prev_throughputs=state.throughputs,
         delivered=delivered0 + tps[:, 2] * params.duration)
-    if sparse:
-        c_objs = default_objectives(max_active) if c_objs is None else c_objs
-        c_delivered0 = jnp.where(
-            valid, delivered0[jnp.minimum(idx, delivered0.shape[0] - 1)],
-            0.0)
-        reward = _fleet_reward(params, c_tps, c_threads,
-                               active_at(c_flows, t_mid), c_objs,
-                               c_delivered0, state.t, bw_ref,
-                               fairness_coef, deadline_coef)
-    else:
-        objs = (default_objectives(state.threads.shape[0])
-                if objectives is None else objectives)
-        reward = _fleet_reward(params, tps, threads,
-                               active_at(flows, t_mid), objs, delivered0,
-                               state.t, bw_ref, fairness_coef,
-                               deadline_coef)
+    with jax.named_scope("topology.reward"):
+        if sparse:
+            c_objs = (default_objectives(max_active) if c_objs is None
+                      else c_objs)
+            c_delivered0 = jnp.where(
+                valid, delivered0[jnp.minimum(idx, delivered0.shape[0] - 1)],
+                0.0)
+            reward = _fleet_reward(params, c_tps, c_threads,
+                                   active_at(c_flows, t_mid), c_objs,
+                                   c_delivered0, state.t, bw_ref,
+                                   fairness_coef, deadline_coef)
+        else:
+            objs = (default_objectives(state.threads.shape[0])
+                    if objectives is None else objectives)
+            reward = _fleet_reward(params, tps, threads,
+                                   active_at(flows, t_mid), objs, delivered0,
+                                   state.t, bw_ref, fairness_coef,
+                                   deadline_coef)
     obs = topology_observe(params, new_state, flows=flows, graph=graph,
                            paths=paths, spec=spec, objectives=objectives,
                            max_active=max_active)

@@ -7,6 +7,8 @@ emit exactly the sim's feature rows (live/sim parity), training must run
 over topologies for all three temporal policies, and the live MultiLink
 must enforce min-over-path and all-or-refund token acquisition."""
 
+import re
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -139,6 +141,82 @@ def test_rate_is_min_over_path_links():
     r_fast = _topology_substep_rates(p, graph, fast, threads, flows, 0.0, 1)
     assert np.allclose(np.asarray(r_both)[0, 0], 1.0)
     assert np.allclose(np.asarray(r_fast)[0, 0], 4.0)
+
+
+def _four_sites():
+    """The four-site DTN deployment of the benchmark
+    (``bench/configs/petascale_dtn_4site.json``): its configuration, the
+    program's graph and static routes built from it, and the benchmark's
+    plain reference of the same solve (``bench/ref/topology.py``)."""
+    import importlib
+    import json
+    import sys
+    from pathlib import Path
+    bench = Path(__file__).resolve().parents[1] / "bench"
+    if str(bench) not in sys.path:
+        sys.path.insert(0, str(bench))
+    ref = importlib.import_module("ref.topology")
+    config = json.loads((bench / "configs" / "petascale_dtn_4site.json")
+                        .read_text())
+    e, g = config["env"], config["graph"]
+    links = g["links"]
+    onpath = [[float(name in f["links"]) for name in links]
+              for f in g["flows"]]
+    shape = (len(links), 1, 3)
+    graph = make_link_graph(np.broadcast_to(e["tpt"], shape),
+                            np.broadcast_to(e["bw"], shape))
+    params = make_env_params(tpt=e["tpt"], bw=e["bw"], cap=e["cap"],
+                             n_max=e["n_max"])
+    return config, params, graph, make_path_spec(onpath), ref
+
+
+def test_four_site_rates_match_a_hand_worked_case_and_the_reference():
+    """ALCF's three outgoing flows (30, 10 and 10 threads) split its
+    100 Gbit/s egress 3:1:1, so 60, 20 and 20 Gbit/s of network stage
+    (each could move 2.5 Gbit/s a thread). ALCF->NCSA is held by that
+    egress share, 60 (its NCSA ingress share is 30/34 of 100 and its
+    threads move 75); ALCF->NERSC by its NERSC ingress share, 10/52 of
+    100 = 19.23, below its egress 20, since NCSA->NERSC brings 40 threads
+    there. The program's solve and the benchmark's reference agree on
+    every flow and stage."""
+    config, params, graph, paths, ref = _four_sites()
+    pairs = [(f["from"], f["to"]) for f in config["graph"]["flows"]]
+    n = {("ALCF", "NCSA"): 30.0, ("ALCF", "NERSC"): 10.0,
+         ("ALCF", "OLCF"): 10.0, ("NCSA", "NERSC"): 40.0}
+    threads = jnp.asarray([[n.get(pr, 2.0)] * 3 for pr in pairs])
+    got = np.asarray(_topology_substep_rates(
+        params, graph, paths, threads, always_on(len(pairs)), 0.0, 1))[0]
+    net = dict(zip(pairs, got[:, 1]))
+    want = {("ALCF", "NCSA"): 60.0, ("ALCF", "NERSC"): 100.0 * 10 / 52,
+            ("ALCF", "OLCF"): 20.0, ("NCSA", "NERSC"): 100.0 * 40 / 52,
+            ("NCSA", "ALCF"): 100.0 * 2 / 44, ("OLCF", "NERSC"): 100.0 * 2 / 52,
+            ("NERSC", "ALCF"): 5.0}
+    for pr, v in want.items():
+        assert net[pr] == pytest.approx(v, rel=1e-6), pr
+    world = ref.world_of(config)
+    np.testing.assert_allclose(np.asarray(ref.rates(threads, world))[0], got,
+                               rtol=1e-6)
+
+
+def test_the_topology_episode_program_names_its_four_scopes():
+    """The solve, the integration, the observation and the reward of the
+    topology rollout carry their named scopes in the episode program's op
+    metadata (``op_name``, as the compiled program holds it)."""
+    from repro.core import ppo
+    from repro.core.topology import Topology
+    _, params, graph, paths, _ = _four_sites()
+    cfg = PPOConfig(max_episodes=2, n_envs=2, n_flows=paths.n_flows,
+                    max_steps=2, substeps=4, obs_spec=TOPOLOGY_OBS)
+    fn = ppo._make_episode_fn(params, cfg, randomize_t0=True, topology=True)
+    state = ppo.init_agent(jax.random.PRNGKey(0), cfg)
+    topo = stack_topologies([Topology(graph, paths)] * cfg.n_envs)
+    flows = ppo._broadcast_table(always_on(cfg.n_flows), cfg.n_envs)
+    text = fn.lower(state, None, flows, None, topo,
+                    jax.random.PRNGKey(1)).compile().as_text()
+    for scope in ("topology.solve", "topology.integrate",
+                  "topology.observe", "topology.reward"):
+        assert re.search(r'op_name="jit\(episode\)/rollout/[^"]*/'
+                         + re.escape(scope) + r'[/"]', text), scope
 
 
 def test_work_conserving_under_caps():
